@@ -24,9 +24,9 @@ bench-smoke:
 
 # Syntax/bytecode check everywhere; upgrade to pyflakes when present.
 lint:
-	$(PYTHON) -m compileall -q src tests benchmarks examples
+	$(PYTHON) -m compileall -q src tests benchmarks examples chip_smoke.py
 	@$(PYTHON) -c "import pyflakes" 2>/dev/null \
-	  && $(PYTHON) -m pyflakes src tests benchmarks examples \
+	  && $(PYTHON) -m pyflakes src tests benchmarks examples chip_smoke.py \
 	  || echo "pyflakes not installed - compileall syntax check only"
 
 # Static PIM-program verifier (DESIGN.md §12) + the semantic proof tier

@@ -152,66 +152,87 @@ MP_ROWS = 64
 MP_REPS = 5
 
 
-def _rs_workload(rng):
+def rs_workload(rng, *, banks: int = MP_BANKS,
+                cw_per_bank: int = MP_CW_PER_BANK, rows: int = MP_ROWS,
+                words: int = MP_WORDS, subarrays: int = 1):
     """The 3-phase RS(12,8) workload: encode (XOR-fold every codeword into
     per-bank accumulator rows — the fold of valid codewords is itself a
     valid codeword), reduce (log2(banks) gather+merge tree down to bank 0),
     readback. Expressed as one heterogeneous phase list for
     ``schedule_workload``; one codeword is corrupted so the folded
-    syndromes are non-zero and detection is observable end-to-end."""
+    syndromes are non-zero and detection is observable end-to-end.
+    Programs run on subarray 0 of each bank of a
+    ``paper_device(banks, subarrays=subarrays)`` of ``rows x words``."""
     from repro.core.bitplane import rs
     from repro.core.pim import isa
     n, npar = 12, 4
-    lanes = MP_WORDS * 32 // 8
+    lanes = words * 32 // 8
     acc, recv, stage = list(range(n)), list(range(n, 2 * n)), 2 * n
-    cw = np.zeros((MP_BANKS, MP_CW_PER_BANK, n, lanes), np.uint64)
-    for b in range(MP_BANKS):
-        for k in range(MP_CW_PER_BANK):
-            msg = rng.integers(0, 256, size=(8, lanes))
-            par = rs.ref_rs_encode(msg, npar)
+    msg = rng.integers(0, 256, size=(banks, cw_per_bank, 8, lanes))
+    cw = np.zeros((banks, cw_per_bank, n, lanes), np.uint64)
+    for b in range(banks):
+        for k in range(cw_per_bank):
+            par = rs.ref_rs_encode(msg[b, k], npar)
             cw[b, k] = np.concatenate(
-                [msg.astype(np.uint64), par[::-1]], axis=0)
-    cw[1, 2, 5, 3] ^= 0x5A          # one corrupted byte lane
+                [msg[b, k].astype(np.uint64), par[::-1]], axis=0)
+    cw[1, min(2, cw_per_bank - 1), 5, 3] ^= 0x5A    # one corrupted byte lane
 
     from repro.core.bitplane import layout as bl
 
     def pack(row):
-        return bl.pack_elements(row, 8, MP_WORDS)
+        return bl.pack_elements(row, 8, words)
 
-    cfg = pim.paper_device(MP_BANKS, num_rows=MP_ROWS, words=MP_WORDS)
-    bi = pim.ProgramBuilder(MP_ROWS, MP_WORDS)
+    cfg = pim.paper_device(banks, num_rows=rows, words=words,
+                           subarrays=subarrays)
+    bi = pim.ProgramBuilder(rows, words)
     for r in acc:
         bi.rowclone(isa.C0, r)
-    phases = [pim.Phase.repeat([bi.build()] * MP_BANKS, 1)]
+    phases = [pim.Phase.repeat([bi.build()] * banks, 1)]
     for j in range(n):                      # encode: fold codeword byte j
-        b = pim.ProgramBuilder(MP_ROWS, MP_WORDS)
+        b = pim.ProgramBuilder(rows, words)
         b.issue()
-        b.write_row(stage, np.zeros(MP_WORDS, np.uint32))
+        b.write_row(stage, np.zeros(words, np.uint32))
         b.ambit_xor(acc[j], stage, acc[j])
         enc = b.build()
         phases.append(pim.Phase(steps=tuple(
             [enc.with_payloads([pack(cw[bk, k, j])])
-             for bk in range(MP_BANKS)]
-            for k in range(MP_CW_PER_BANK))))
-    bm = pim.ProgramBuilder(MP_ROWS, MP_WORDS)
+             for bk in range(banks)]
+            for k in range(cw_per_bank))))
+    bm = pim.ProgramBuilder(rows, words)
     for j in range(n):
         bm.ambit_xor(acc[j], recv[j], acc[j])
     merge = bm.build()
     stride = 1
-    while stride < MP_BANKS:                # reduce: gather+merge tree
+    while stride < banks:                   # reduce: gather+merge tree
         moves = [((b + stride, 0, acc[j]), (b, 0, recv[j]))
-                 for b in range(0, MP_BANKS, 2 * stride) for j in range(n)]
+                 for b in range(0, banks, 2 * stride) for j in range(n)]
         phases.append(pim.Phase.repeat(pim.gather_rows(cfg, moves), 1))
-        alive = set(range(0, MP_BANKS, 2 * stride))
+        alive = set(range(0, banks, 2 * stride))
         phases.append(pim.Phase.repeat(
-            [merge if b in alive else None for b in range(MP_BANKS)], 1))
+            [merge if b in alive else None for b in range(banks)], 1))
         stride *= 2
-    br = pim.ProgramBuilder(MP_ROWS, MP_WORDS)
+    br = pim.ProgramBuilder(rows, words)
     for j in range(n):
         br.read_row(acc[j])
     phases.append(pim.Phase.repeat(
-        [br.build()] + [None] * (MP_BANKS - 1), 1))
+        [br.build()] + [None] * (banks - 1), 1))
     return cfg, phases, cw, acc
+
+
+def rs_check(state, cw, acc, words: int = MP_WORDS):
+    """``(bit_exact, detected)`` for a finished :func:`rs_workload`: the
+    folded codeword in bank 0 against the numpy XOR oracle, and whether
+    its RS syndromes flag the injected corruption."""
+    from repro.core.bitplane import layout as bl
+    from repro.core.bitplane import rs
+    lanes = words * 32 // 8
+    bits = np.asarray(state.slot(0).bits)
+    got = np.stack([bl.unpack_elements(bits[acc][j], 8, lanes)
+                    for j in range(len(acc))])
+    oracle = np.bitwise_xor.reduce(
+        cw.reshape(-1, len(acc), lanes).astype(np.uint64), axis=0)
+    return (bool(np.array_equal(got, oracle)),
+            bool(np.any(rs.ref_rs_syndromes(got, 4))))
 
 
 def bench_multi_phase(report=print):
@@ -220,10 +241,8 @@ def bench_multi_phase(report=print):
     dispatch per phase step, the O(phases x steps) baseline
     ``schedule_workload`` replaces. The ``schedule_pipeline``-per-phase
     loop (O(phases) dispatches) is reported as an extra datum."""
-    from repro.core.bitplane import layout as bl
-    from repro.core.bitplane import rs
     rng = np.random.default_rng(0)
-    cfg, phases, cw, acc = _rs_workload(rng)
+    cfg, phases, cw, acc = rs_workload(rng)
     n_steps = sum(len(p.steps) for p in phases)
     stats = pim_schedule.SCHED_STATS
 
@@ -234,14 +253,7 @@ def bench_multi_phase(report=print):
 
     # Correctness: the in-DRAM fold must equal the numpy XOR oracle, and
     # the folded syndromes must flag the injected corruption.
-    lanes = MP_WORDS * 32 // 8
-    got = np.stack([bl.unpack_elements(
-        np.asarray(res.state.slot(0).bits)[acc][j], 8, lanes)
-        for j in range(len(acc))])
-    oracle = np.bitwise_xor.reduce(
-        cw.reshape(-1, len(acc), lanes).astype(np.uint64), axis=0)
-    bit_exact = np.array_equal(got, oracle)
-    detected = bool(np.any(rs.ref_rs_syndromes(got, 4)))
+    bit_exact, detected = rs_check(res.state, cw, acc)
 
     # Per-phase dispatch loop reference (also warms every step layout).
     seq = [s for p in phases for s in p.steps]

@@ -34,6 +34,15 @@ def _gf_mul_scalar(a: int, b: int) -> int:
     return int(_EXP[(int(_LOG[a]) + int(_LOG[b])) % 255])
 
 
+def _gf_mul_vec(a: np.ndarray, b: int) -> np.ndarray:
+    """``_gf_mul_scalar`` lane-wise: every symbol of ``a`` times ``b``."""
+    a = np.asarray(a, dtype=np.uint64)
+    if b == 0:
+        return np.zeros_like(a)
+    prod = _EXP[(_LOG[a] + _LOG[b]) % 255]
+    return np.where(a == 0, np.uint64(0), prod)
+
+
 def generator_poly(n_parity: int) -> list[int]:
     """g(x) = prod_{i=0}^{n_parity-1} (x - alpha^i); returns coeffs low→high,
     excluding the leading (monic) term."""
@@ -58,9 +67,7 @@ def ref_rs_encode(msg: np.ndarray, n_parity: int) -> np.ndarray:
         shifted = np.zeros_like(parity)
         shifted[1:] = parity[:-1]
         for j in range(n_parity):
-            mul = np.array([_gf_mul_scalar(int(f), gcoef[j]) for f in fb],
-                           dtype=np.uint64)
-            shifted[j] ^= mul
+            shifted[j] ^= _gf_mul_vec(fb, gcoef[j])
         parity = shifted
     return parity
 
@@ -74,8 +81,7 @@ def ref_rs_syndromes(codeword: np.ndarray, n_parity: int) -> np.ndarray:
         alpha_i = int(_EXP[i])
         acc = np.zeros(lanes, dtype=np.uint64)
         for sym in codeword:
-            acc = np.array([_gf_mul_scalar(int(a), alpha_i) for a in acc],
-                           dtype=np.uint64) ^ sym
+            acc = _gf_mul_vec(acc, alpha_i) ^ sym
         out[i] = acc
     return out
 

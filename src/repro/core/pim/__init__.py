@@ -26,7 +26,7 @@ from .device import (DeviceConfig, DeviceState, bus_time_ns,
                      host_bus_ns, issue_bus_ns, make_device, paper_device)
 from .schedule import (CopyDrainStats, Phase, PhaseResult, PipelinePlan,
                        PipelineResult, ScheduleResult, WorkloadResult,
-                       compiled_for, gather_rows, schedule,
+                       clear_caches, compiled_for, gather_rows, schedule,
                        schedule_pipeline, schedule_workload, shard_lanes,
                        shard_rows, stream_key, xor_reduce_program)
 from .lint import (CATALOG, Diagnostic, LintError, LintReport, lint_program,
@@ -76,7 +76,8 @@ __all__ = [
     "channel_occupancy", "device_wall_ns", "host_bus_ns", "issue_bus_ns",
     "make_device", "paper_device",
     "CopyDrainStats", "Phase", "PhaseResult", "PipelinePlan",
-    "PipelineResult", "ScheduleResult", "WorkloadResult", "compiled_for",
+    "PipelineResult", "ScheduleResult", "WorkloadResult", "clear_caches",
+    "compiled_for",
     "gather_rows", "schedule", "schedule_pipeline", "schedule_workload",
     "shard_lanes", "shard_rows", "stream_key", "xor_reduce_program",
     "CATALOG", "Diagnostic", "LintError", "LintReport", "lint_program",

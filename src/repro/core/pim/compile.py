@@ -220,36 +220,17 @@ def cost_tables(program: ir.PimProgram,
 #
 # Each float add sits behind jax.lax.optimization_barrier: XLA's CPU
 # fast-math would otherwise reassociate the unrolled chain into SIMD
-# partial sums and drift from the eager meter by ulps. jax 0.4.x has no
-# vmap batching rule for the barrier primitive, but it is an identity
-# primitive, so the passthrough rule (the one upstream later added) is
-# registered here; without it the fold falls back to row-at-a-time blocks,
-# which need no barrier.
+# partial sums and drift from the eager meter by ulps. JAX batches the
+# barrier natively, so the fold stays exact under the scheduler's vmap.
 _FOLD_BLOCK = 64
 
 
-def _register_barrier_batching() -> bool:
-    try:
-        from jax._src.lax.lax import optimization_barrier_p as p
-        from jax.interpreters import batching
-        if p not in batching.primitive_batchers:
-            batching.primitive_batchers[p] = (
-                lambda args, dims: (p.bind(*args), dims))
-        return True
-    except Exception:           # pragma: no cover - future-jax safety net
-        return False
-
-
-_BARRIER_OK = _register_barrier_batching()
-
-
-@functools.partial(jax.jit, static_argnames=())
+@jax.jit
 def _fold_tables(f_tab, i_tab, f0, i0):
     n = f_tab.shape[0]
     if n == 0:
         return f0, i0
-    block = _FOLD_BLOCK if _BARRIER_OK else 1
-    pad = (-n) % block
+    pad = (-n) % _FOLD_BLOCK
     if pad:
         f_tab = jnp.concatenate(
             [f_tab, jnp.zeros((pad, f_tab.shape[1]), f_tab.dtype)])
@@ -259,17 +240,15 @@ def _fold_tables(f_tab, i_tab, f0, i0):
     def step(carry, blk):
         cf, ci = carry
         bf, bi = blk
-        for j in range(block):          # unrolled inside the loop body
-            cf = cf + bf[j]
-            if _BARRIER_OK:
-                cf = jax.lax.optimization_barrier(cf)
+        for j in range(_FOLD_BLOCK):          # unrolled inside the loop body
+            cf = jax.lax.optimization_barrier(cf + bf[j])
             ci = ci + bi[j]
         return (cf, ci), ()
 
     (ff, fi), _ = jax.lax.scan(
         step, (f0, i0),
-        (f_tab.reshape(-1, block, f_tab.shape[1]),
-         i_tab.reshape(-1, block, i_tab.shape[1])))
+        (f_tab.reshape(-1, _FOLD_BLOCK, f_tab.shape[1]),
+         i_tab.reshape(-1, _FOLD_BLOCK, i_tab.shape[1])))
     return ff, fi
 
 

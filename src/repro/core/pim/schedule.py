@@ -497,8 +497,13 @@ class _StepPlan:
     lint: tuple = ()
 
 
+# Each cached plan keeps its compiled step program alive. On the XLA CPU
+# backend one such program holds ~290 memory mappings (measured on the
+# 4-slot differential-harness layouts), and a process may hold at most
+# vm.max_map_count (65,530 by default): past it, XLA's code allocator
+# fails and the next compile crashes the process. 64 plans stay far below.
 _plan_cache: dict = {}
-_PLAN_CACHE_MAX = 256
+_PLAN_CACHE_MAX = 64
 
 
 def _plan_key(cfg: DeviceConfig, groups, deferred, *,
@@ -1150,6 +1155,19 @@ _PHASE_LOWER_CACHE_MAX = 256
 # recycled id can never alias a dead layout.
 _workload_fast_cache: dict = {}
 _WORKLOAD_FAST_CACHE_MAX = 32
+
+
+def clear_caches() -> None:
+    """Drop every scheduler cache — compiled streams, step plans, pipeline
+    and workload drivers, lowering memos and pinned payload batches — and
+    with them the compiled programs they keep alive. The next call of each
+    layout lowers and compiles again; results are unchanged."""
+    for cache in (_compile_cache, _plan_cache, _pipeline_cache,
+                  _workload_plan_cache, _workload_fn_cache,
+                  _phase_lower_cache, _workload_fast_cache):
+        cache.clear()
+    _payload_cache_clear()
+    _copy_drain_plan.cache_clear()
 
 
 def _layout_ids(step):

@@ -1,16 +1,1 @@
 """Pallas TPU kernels (validated in interpret mode on CPU hosts)."""
-from __future__ import annotations
-
-
-def pallas_compiler_params(**kwargs):
-    """Build Pallas TPU compiler params across the JAX rename
-    (TPUCompilerParams -> CompilerParams); raises clearly when neither
-    exists instead of failing with a NoneType call."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        raise ImportError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-            "TPUCompilerParams; unsupported JAX version")
-    return cls(**kwargs)

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import pallas_compiler_params
-
 NEG_INF = -1e30
 DEFAULT_BQ = 256
 DEFAULT_BK = 512
@@ -109,7 +107,7 @@ def flash_attention_fwd(q, k, v, pos_q, pos_k, *, window=None,
             pltpu.VMEM((bq,), jnp.float32),       # running denom
             pltpu.VMEM((bq, dh), jnp.float32),    # running accumulator
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos_q, pos_k, q, k, v)

@@ -27,7 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from .. import pallas_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 from .ref import plane_coeffs
 
@@ -78,7 +78,7 @@ def pim_matmul_raw(x, w_int, *, mode: str, bits: int,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_int)

@@ -33,6 +33,10 @@ from repro.models import decode_step, init_caches, init_params, prefill
 from repro.optim import adamw
 from repro.train.step import init_train_state, make_train_step
 
+# The chip the dry-run models: its peaks set the roofline terms, whatever
+# (virtual CPU) devices the compile runs on.
+TARGET_KIND = "TPU v5 lite"
+
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
 
@@ -205,7 +209,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
             model_flops = rl.analytic_model_flops(
                 cfg, shape.kind, shape.seq_len, shape.global_batch)
             roof, coll = rl.from_compiled(compiled, n_dev, model_flops,
-                                          hlo_text=hlo)
+                                          TARGET_KIND, hlo_text=hlo)
             try:
                 mem = compiled.memory_analysis()
                 mem_rec = {k: int(getattr(mem, k)) for k in
@@ -239,8 +243,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
                 # score-carrying fraction: ideal time (compute OR unavoidable
                 # memory, whichever binds) over the achieved bound
                 "roofline_fraction_cell": float(
-                    max(model_flops / n_dev / rl.PEAK_FLOPS,
-                        bytes_acct["ideal_step_bytes"] / rl.HBM_BW)
+                    max(model_flops / n_dev / roof.peaks.flops,
+                        bytes_acct["ideal_step_bytes"] / roof.peaks.hbm_bw)
                     / max(roof.bound_time, 1e-30)),
                 "memory_analysis": mem_rec,
                 "sharding_report": {

@@ -1,6 +1,7 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Three terms per (arch × shape × mesh), TPU v5e constants:
+Three terms per (arch × shape × mesh), from the per-chip peaks of the
+target device kind (``PEAKS``; TPU v5e numbers shown):
 
     T_compute    = HLO_FLOPs / (chips · 197e12)          [bf16 peak]
     T_memory     = HLO_bytes / (chips · 819e9)           [HBM BW]
@@ -27,10 +28,35 @@ from __future__ import annotations
 import dataclasses
 import re
 
-# TPU v5e
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-LINK_BW = 50e9               # bytes/s / link (ICI)
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops: float                 # bf16 FLOP/s
+    hbm_bw: float                # HBM bytes/s
+    link_bw: float               # ICI bytes/s per link
+    source: str
+
+
+# Keyed by ``jax.Device.device_kind``.
+PEAKS = {
+    "TPU v5 lite": Peaks(
+        flops=197e12, hbm_bw=819e9, link_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               '819 GB/s HBM, 1,600 Gbit/s ICI per chip over 4 links'),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peaks of ``device_kind``; a kind without published peaks is an
+    error, never a silent default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r} (known: {sorted(PEAKS)})") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "s32": 4, "u32": 4,
@@ -121,18 +147,19 @@ class Roofline:
     link_bytes: float            # per-chip collective link traffic
     chips: int
     model_flops: float           # analytic global model flops
+    peaks: Peaks
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.link_bytes / LINK_BW
+        return self.link_bytes / self.peaks.link_bw
 
     @property
     def bottleneck(self) -> str:
@@ -148,7 +175,7 @@ class Roofline:
     def roofline_fraction(self) -> float:
         """Useful-compute fraction of the bound: (MODEL_FLOPS/chips/peak) /
         max-term — the score-carrying number (1.0 = perfect)."""
-        ideal = self.model_flops / self.chips / PEAK_FLOPS
+        ideal = self.model_flops / self.chips / self.peaks.flops
         return ideal / max(self.bound_time, 1e-30)
 
     @property
@@ -158,6 +185,7 @@ class Roofline:
 
 
 def from_compiled(compiled, n_devices: int, model_flops: float,
+                  device_kind: str,
                   hlo_text: str | None = None) -> tuple[Roofline, dict]:
     """Terms via the loop-aware HLO analyzer (hlo_analysis.py). The SPMD
     module is already per-device, so no /n_devices normalization is applied
@@ -178,7 +206,7 @@ def from_compiled(compiled, n_devices: int, model_flops: float,
         pass
     rl = Roofline(flops=cost.flops, hbm_bytes=cost.hbm,
                   link_bytes=cost.link, chips=n_devices,
-                  model_flops=model_flops)
+                  model_flops=model_flops, peaks=peaks(device_kind))
     return rl, coll
 
 

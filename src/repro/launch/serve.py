@@ -21,7 +21,10 @@ def main():
     args = ap.parse_args()
 
     cfg = get_config(args.arch, smoke=args.smoke)
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    # jitted: the float32 draws fuse into the bf16 weights instead of
+    # materializing at full width next to them
+    params = jax.jit(init_params, static_argnums=0)(cfg,
+                                                   jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     prompts = {"tokens": jnp.asarray(
         rng.integers(0, cfg.vocab_size, (args.batch, args.prompt_len)),

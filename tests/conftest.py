@@ -10,10 +10,16 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 def _fresh_pim_stats():
     """Zero the pim instrumentation counters (COLUMN_STATS / SCHED_STATS /
     RUNNER_STATS) before every test so stats-asserting tests are
-    order-independent — any test may touch the cached schedule paths."""
+    order-independent — any test may touch the cached schedule paths.
+
+    Also drop the scheduler caches: each cached plan keeps a compiled
+    program alive, and one worker running many tests would otherwise
+    gather them until the process runs out of memory mappings and the next
+    XLA compile crashes it."""
     import repro.core.pim as pim
 
     pim.reset_stats()
+    pim.clear_caches()
     yield
 
 try:  # hypothesis is optional: clean environments still run the example tests

@@ -192,6 +192,36 @@ def test_fold_block_matches_row_at_a_time():
         assert np.array_equal(np.asarray(fi), ref_i), n
 
 
+def test_fold_blocks_match_cost_tables_reference():
+    """A real stream whose event count is no multiple of the 64-row fold
+    block, folded in-jit under vmap from two nonzero meters, equals the
+    strictly sequential fold of the per-op reference tables bit for bit —
+    the blocked path and its zero padding both run."""
+    rng = np.random.default_rng(31)
+    b = pim.ProgramBuilder(ROWS, WORDS)
+    b.issue()
+    b.write_row(0, _rand_row(rng))
+    b.shift_k(0, 1, 21)
+    b.ambit_xor(0, 1, 2)
+    b.read_row(2)
+    f_ref, i_ref = pim.cost_tables_reference(b.build())
+    assert len(f_ref) > pim_compile._FOLD_BLOCK
+    assert len(f_ref) % pim_compile._FOLD_BLOCK
+    f0 = rng.uniform(0, 1e4, (2, 6)).astype(np.float32)
+    i0 = rng.integers(0, 100, (2, 6), dtype=np.int32)
+    ff, fi = jax.vmap(pim_compile._fold_tables, in_axes=(None, None, 0, 0))(
+        jnp.asarray(f_ref), jnp.asarray(i_ref), jnp.asarray(f0),
+        jnp.asarray(i0))
+    for k in range(2):
+        want_f = np.add.accumulate(np.concatenate([f0[k][None], f_ref]),
+                                   axis=0, dtype=np.float32)[-1]
+        want_i = np.add.accumulate(np.concatenate([i0[k][None], i_ref]),
+                                   axis=0, dtype=np.int32)[-1]
+        assert np.array_equal(np.asarray(ff[k]).view(np.uint32),
+                              want_f.view(np.uint32)), k
+        assert np.array_equal(np.asarray(fi[k]), want_i), k
+
+
 # ---------------------------------------------------------------------------
 # Single-dispatch schedule: compile/dispatch count guards
 # ---------------------------------------------------------------------------
@@ -355,6 +385,9 @@ def test_workload_fast_cache_pins_key_steps():
     cfg = _cfg(banks_per_rank=2)
     dev = pim.make_device(cfg)
     base = _step_prog(_rand_row(rng))
+    # the compile cache keeps one representative program per stream: let
+    # it be `base`, so only the layout caches can pin the layout below
+    pim.compiled_for(base)
     layout = [base.with_payloads([_rand_row(rng)]) for _ in range(2)]
     phases = [pim_schedule.Phase.repeat(layout, 2)]
     pim.schedule_workload(dev, phases)

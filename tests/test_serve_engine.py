@@ -1,10 +1,14 @@
 """Serving engine behaviours beyond the system test."""
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
 from repro.models import init_params
+from repro.serve import engine
 from repro.serve.engine import DECODE_STATS, greedy_generate
 
 from util import make_inputs
@@ -58,6 +62,56 @@ def test_decode_loop_is_single_dispatch(setup):
     assert DECODE_STATS["dispatches"] == 1
     assert out1.shape == (2, 8)
     assert jnp.array_equal(out1, out2)      # greedy decode is deterministic
+
+
+def test_repeat_generate_traces_decode_once(setup):
+    """The decode loop is one module-level jit taking the weights and caches
+    as arguments: a second request with the same shapes reuses it."""
+    cfg, params = setup
+    prompts = make_inputs(cfg, 3, 13, labels=False)   # shapes no other test
+    DECODE_STATS["traces"] = 0
+    first = greedy_generate(cfg, params, prompts, max_new_tokens=5)
+    second = greedy_generate(cfg, params, prompts, max_new_tokens=5)
+    assert DECODE_STATS["traces"] == 1
+    assert jnp.array_equal(first, second)
+
+
+def _largest_constant(hlo_text: str) -> int:
+    """Element count of the largest ``stablehlo.constant`` in the text."""
+    sizes = [math.prod(int(d) for d in dims.split("x")[:-1])
+             for dims in re.findall(
+                 r"stablehlo\.constant dense[^\n]*?: tensor<([^>]*)>",
+                 hlo_text)]
+    return max(sizes, default=0)
+
+
+def test_weights_reach_the_programs_as_arguments(setup):
+    """Neither the prefill nor the decode program bakes a weight in as a
+    constant (which also recompiled every request)."""
+    cfg, params = setup
+    prompts = make_inputs(cfg, 2, 8, labels=False)
+    smallest = min(x.size for x in jax.tree_util.tree_leaves(params)
+                   if x.ndim >= 2 and min(x.shape[-2:]) > 1)
+    lowered_prefill = engine._prefill.lower(cfg, params, prompts,
+                                            max_cache_len=12)
+    logits, caches = engine._prefill(cfg, params, prompts, max_cache_len=12)
+    lowered_decode = engine._decode.lower(
+        cfg, params, logits, caches, jax.random.PRNGKey(0), jnp.int32(8),
+        max_new_tokens=4, temperature=0.0)
+    for lowered in (lowered_prefill, lowered_decode):
+        assert _largest_constant(lowered.as_text()) < smallest
+
+
+def test_return_logits_are_the_last_sampled_step(setup):
+    """``return_logits`` hands back the logits the last token came from:
+    their argmax is that token under greedy decoding."""
+    cfg, params = setup
+    prompts = make_inputs(cfg, 2, 8, labels=False)
+    tokens, logits = greedy_generate(cfg, params, prompts, max_new_tokens=3,
+                                     return_logits=True)
+    assert logits.dtype == jnp.float32
+    last = jnp.argmax(logits.reshape(2, -1)[:, :cfg.vocab_size], axis=-1)
+    assert jnp.array_equal(last, tokens[:, -1])
 
 
 def test_zero_new_tokens_returns_empty(setup):

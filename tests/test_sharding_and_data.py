@@ -2,12 +2,13 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
 from repro.data.pipeline import Prefetcher, make_batch
 from repro.data.synthetic import SyntheticTokens
-from repro.launch import sharding
+from repro.launch import roofline, sharding
 from repro.launch.hlo_analysis import HloModule, analyze, shape_bytes
 from repro.launch.mesh import make_host_mesh
 from repro.models import init_params
@@ -142,3 +143,13 @@ ENTRY %main (p: f32[64]) -> f32[64] {
     c = m.entry_cost()
     assert c.coll["all-reduce"] == 2 * 256 * 3 / 4
     assert c.coll["all-gather"] == 1024 * 3 / 4
+
+
+# --- roofline peaks -----------------------------------------------------------
+
+def test_peaks_are_keyed_by_device_kind():
+    v5e = roofline.peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(KeyError, match="cpu"):
+        roofline.peaks("cpu")          # an unknown kind is never defaulted
