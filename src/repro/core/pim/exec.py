@@ -210,46 +210,64 @@ def _scan_segment(seg: _SegTable, carry, num_rows: int):
 # Segment walk
 # ---------------------------------------------------------------------------
 
+# The named scope of each segment kind: the part of the runner its
+# operations belong to, in the compiled program and the device trace.
+_SEG_SCOPE = {SegShiftRun: "pim.runner.row_math",
+              SegMaj: "pim.runner.row_math",
+              SegNot: "pim.runner.row_math",
+              _SegTable: "pim.runner.residual_scan",
+              SegHost: "pim.runner.host_io"}
+
+
 def _run_segments(compiled: CompiledProgram, carry, use_kernels, interpret,
                   payloads=None):
     reads = []
     if payloads is None:
         payloads = [jnp.asarray(p) for p in compiled.program.payloads]
     for seg in _coalesce(compiled.segments, use_kernels):
-        bits, mt, mb, dcc = carry
-        if isinstance(seg, SegShiftRun):
-            # k chained 1-bit shifts: shift (k-1) columns in one kernel call,
-            # then replay the last hop so mig_top/mig_bot match eager exactly.
-            y = _shift_row(bits[seg.src], seg.delta * (seg.k - 1),
-                           use_kernels, interpret)
-            mt = y & (EVEN_MASK if seg.delta > 0 else ODD_MASK)
-            mb = y & (ODD_MASK if seg.delta > 0 else EVEN_MASK)
-            merged = _shift1(mt, seg.delta) | _shift1(mb, seg.delta)
-            carry = (bits.at[seg.dst].set(merged), mt, mb, dcc)
-        elif isinstance(seg, SegMaj):
-            m = _maj_rows(bits[seg.a], bits[seg.b], bits[seg.c],
-                          use_kernels, interpret)
-            t0, t1, t2 = (t % compiled.num_rows
-                          for t in (isa_T0, isa_T1, isa_T2))
-            bits = bits.at[t0].set(m).at[t1].set(m).at[t2].set(m)
-            carry = (bits.at[seg.dst].set(m), mt, mb, dcc)
-        elif isinstance(seg, SegNot):
-            dcc = _not_row(bits[seg.src], use_kernels, interpret)
-            carry = (bits.at[seg.dst].set(dcc), mt, mb, dcc)
-        elif isinstance(seg, _SegTable):
-            carry = _scan_segment(seg, carry, compiled.num_rows)
-        elif isinstance(seg, SegHost):
-            op = seg.op
-            if op.op == ir.OP_READ:
-                reads.append(bits[op.a])
-            elif op.op == ir.OP_WRITE:
-                carry = (bits.at[op.b].set(payloads[op.payload]), mt, mb, dcc)
-            elif op.op == ir.OP_FILL:
-                row = jnp.full((compiled.words,), jnp.uint32(op.payload))
-                carry = (bits.at[op.b].set(row), mt, mb, dcc)
-        else:
-            raise TypeError(seg)
+        with jax.named_scope(_SEG_SCOPE.get(type(seg), "pim.runner")):
+            carry = _run_segment(compiled, seg, carry, reads, use_kernels,
+                                 interpret, payloads)
     return carry, tuple(reads)
+
+
+def _run_segment(compiled: CompiledProgram, seg, carry, reads: list,
+                 use_kernels, interpret, payloads):
+    """One segment's operations on ``carry``; host reads append to
+    ``reads``. Returns the new carry."""
+    bits, mt, mb, dcc = carry
+    if isinstance(seg, SegShiftRun):
+        # k chained 1-bit shifts: shift (k-1) columns in one kernel call,
+        # then replay the last hop so mig_top/mig_bot match eager exactly.
+        y = _shift_row(bits[seg.src], seg.delta * (seg.k - 1),
+                       use_kernels, interpret)
+        mt = y & (EVEN_MASK if seg.delta > 0 else ODD_MASK)
+        mb = y & (ODD_MASK if seg.delta > 0 else EVEN_MASK)
+        merged = _shift1(mt, seg.delta) | _shift1(mb, seg.delta)
+        return bits.at[seg.dst].set(merged), mt, mb, dcc
+    if isinstance(seg, SegMaj):
+        m = _maj_rows(bits[seg.a], bits[seg.b], bits[seg.c],
+                      use_kernels, interpret)
+        t0, t1, t2 = (t % compiled.num_rows
+                      for t in (isa_T0, isa_T1, isa_T2))
+        bits = bits.at[t0].set(m).at[t1].set(m).at[t2].set(m)
+        return bits.at[seg.dst].set(m), mt, mb, dcc
+    if isinstance(seg, SegNot):
+        dcc = _not_row(bits[seg.src], use_kernels, interpret)
+        return bits.at[seg.dst].set(dcc), mt, mb, dcc
+    if isinstance(seg, _SegTable):
+        return _scan_segment(seg, carry, compiled.num_rows)
+    if isinstance(seg, SegHost):
+        op = seg.op
+        if op.op == ir.OP_READ:
+            reads.append(bits[op.a])
+        elif op.op == ir.OP_WRITE:
+            return bits.at[op.b].set(payloads[op.payload]), mt, mb, dcc
+        elif op.op == ir.OP_FILL:
+            row = jnp.full((compiled.words,), jnp.uint32(op.payload))
+            return bits.at[op.b].set(row), mt, mb, dcc
+        return carry
+    raise TypeError(seg)
 
 
 def make_runner(program, cfg: DDR3Timing = DEFAULT_TIMING, *,
@@ -262,7 +280,10 @@ def make_runner(program, cfg: DDR3Timing = DEFAULT_TIMING, *,
 
     The returned runner is cached per (program, flags, cfg-value) and is
     vmap-able, so ``bank_parallel`` maps ONE compiled program across banks
-    instead of re-tracing the eager interpreter per bank.
+    instead of re-tracing the eager interpreter per bank. Its operations
+    carry the named scopes ``pim.runner.row_math`` (fused shift runs,
+    MAJ, NOT), ``pim.runner.residual_scan``, ``pim.runner.host_io`` and
+    ``pim.runner.meter_fold`` in the compiled program and the device trace.
 
     With ``payload_arg=True`` the runner takes HOSTW payloads as a second
     argument — a ``(n_payloads, words)`` uint32 array — instead of baking
@@ -303,18 +324,20 @@ def make_runner(program, cfg: DDR3Timing = DEFAULT_TIMING, *,
         carry = (state.bits, state.mig_top, state.mig_bot, state.dcc)
         (bits, mt, mb, dcc), reads = _run_segments(
             compiled, carry, use_kernels, interpret, payloads=payloads)
-        f0 = jnp.stack([jnp.asarray(getattr(state.meter, k), jnp.float32)
-                        for k in pim_compile._FLOAT_FIELDS])
-        i0 = jnp.stack([jnp.asarray(getattr(state.meter, k), jnp.int32)
-                        for k in pim_compile._INT_FIELDS])
-        ff, fi = pim_compile._fold_tables(f_tab, i_tab, f0, i0)
-        fields = {k: ff[j]
-                  for j, k in enumerate(pim_compile._FLOAT_FIELDS)}
-        fields.update({k: fi[j]
-                       for j, k in enumerate(pim_compile._INT_FIELDS)})
-        meter = type(state.meter)(**fields)
-        if refresh:
-            meter = apply_refresh(meter, cfg)
+        with jax.named_scope("pim.runner.meter_fold"):
+            f0 = jnp.stack([jnp.asarray(getattr(state.meter, k),
+                                        jnp.float32)
+                            for k in pim_compile._FLOAT_FIELDS])
+            i0 = jnp.stack([jnp.asarray(getattr(state.meter, k), jnp.int32)
+                            for k in pim_compile._INT_FIELDS])
+            ff, fi = pim_compile._fold_tables(f_tab, i_tab, f0, i0)
+            fields = {k: ff[j]
+                      for j, k in enumerate(pim_compile._FLOAT_FIELDS)}
+            fields.update({k: fi[j]
+                           for j, k in enumerate(pim_compile._INT_FIELDS)})
+            meter = type(state.meter)(**fields)
+            if refresh:
+                meter = apply_refresh(meter, cfg)
         return SubarrayState(bits=bits, mig_top=mt, mig_bot=mb, dcc=dcc,
                              meter=meter), reads
 

@@ -88,20 +88,22 @@ def _unbatch_reads(group_reads, read_layout, n_steps=None):
     """Shared lazy read unbatching: ONE device->host transfer per group
     read array, then plain numpy slicing into the per-slot layout. With
     ``n_steps`` the arrays carry a leading step axis and a per-step list
-    is returned."""
+    is returned. Runs inside the ``pim.sched.reads`` span."""
     n_slots, group_slots = read_layout
-    host = [tuple(np.asarray(r) for r in g) for g in group_reads]
 
-    def one_step(pick):
+    def one_step(host, pick):
         out: list = [()] * n_slots
         for g, slots in enumerate(group_slots):
             for j, k in enumerate(slots):
                 out[k] = tuple(pick(r, j) for r in host[g])
         return tuple(out)
 
-    if n_steps is None:
-        return one_step(lambda r, j: r[j])
-    return [one_step(lambda r, j, k=k: r[k, j]) for k in range(n_steps)]
+    with jax.profiler.TraceAnnotation("pim.sched.reads"):
+        host = [tuple(np.asarray(r) for r in g) for g in group_reads]
+        if n_steps is None:
+            return one_step(host, lambda r, j: r[j])
+        return [one_step(host, lambda r, j, k=k: r[k, j])
+                for k in range(n_steps)]
 
 
 @dataclasses.dataclass
@@ -178,7 +180,38 @@ def stream_key(p: PimProgram):
 #                    (the acceptance bar is <= 1 per steady-state step)
 #   plan_misses    — step-plan cache misses (a new schedule layout)
 #   compile_misses — _compiled_for cache misses (a new program stream)
-SCHED_STATS = {"dispatches": 0, "plan_misses": 0, "compile_misses": 0}
+#   upload_bytes   — bytes of HOSTW payload stacks _payload_stack put on the
+#                    device (payload-cache misses only)
+SCHED_STATS = {"dispatches": 0, "plan_misses": 0, "compile_misses": 0,
+               "upload_bytes": 0}
+
+
+# Host spans, written into the profiler's trace beside the device events
+# (so an idle gap of the device can be put down to what the host did):
+#   pim.sched.{schedule,pipeline,workload}   one entry-point call
+#     pim.sched.lower      normalize, strip copies, group by stream key
+#     pim.sched.plan       the step-plan lookup (and build, on a miss)
+#     pim.sched.payloads   payload stacks: the cache, the upload, stacking
+#     pim.sched.dispatch   the jitted driver's lookup and its call
+#   pim.sched.reads        a result's reads brought to the host
+# A span costs about a microsecond while no trace runs, so spans mark
+# layer boundaries only, never a loop over slots or ops.
+def _span(name: str):
+    """A profiler span tagged ``call=`` with the ordinal of the dispatch the
+    current entry-point call makes: the entry's span and its children share
+    it."""
+    return jax.profiler.TraceAnnotation(name, call=SCHED_STATS["dispatches"])
+
+
+def _spanned(name: str):
+    """Decorator: run the function inside ``_span(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with _span(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
 
 
 # One compiled artifact per distinct (stream, timing): groups recur across
@@ -287,6 +320,7 @@ def _payload_stack(programs: Sequence[PimProgram], words: int) -> jnp.ndarray:
             stacked = jnp.asarray(np.stack(
                 [np.stack(p.payloads) for p in programs]).astype(np.uint32))
             refs = tuple(p.payloads for p in programs)
+            SCHED_STATS["upload_bytes"] += int(stacked.nbytes)
         _payload_cache_put(key, (stacked, refs))
         return stacked
     return hit[0]
@@ -521,7 +555,10 @@ def _make_step_fn(cfg: DeviceConfig, runners, group_slots, bus_j,
                   copy_independent, async_host):
     """Build the single-dispatch jitted step: every stream group's vmapped
     run, the COPY drain (bits scatter + meter bump), and the channel-bus
-    fold — one traced computation, one XLA dispatch per call."""
+    fold — one traced computation, one XLA dispatch per call. The drain and
+    the fold carry the named scopes ``pim.step.copy_drain`` and
+    ``pim.step.bus_fold`` (the runners carry ``pim.runner.*``), which name
+    their operations in the compiled program and the device trace."""
     n_slots = cfg.n_slots
     bus_j_c = jnp.asarray(bus_j)
     busy0_c = jnp.asarray(chan_busy0, jnp.float32)
@@ -529,6 +566,33 @@ def _make_step_fn(cfg: DeviceConfig, runners, group_slots, bus_j,
     p_bg = jnp.float32(cfg.timing.p_background)
     idx_arrays = [jnp.asarray(np.asarray(slots)) for slots in group_slots]
     makespan = jnp.float32(copy_plan.stats.makespan_ns if copy_plan else 0.0)
+
+    def drain(banks):
+        """The COPY drain: the moved rows' scatter and the copies' meter
+        charges."""
+        bits = banks.bits
+        si, sr, di, dr = copy_moves
+        if copy_independent:
+            # Independent copies (the common gather pattern: distinct
+            # destinations, none feeding a later copy) — ONE batched
+            # scatter instead of a row-at-a-time chain.
+            bits = bits.at[jnp.asarray(di), jnp.asarray(dr)].set(
+                bits[jnp.asarray(si), jnp.asarray(sr)])
+        else:
+            for s_slot, s_row, d_slot, d_row in zip(si, sr, di, dr):
+                bits = bits.at[d_slot, d_row].set(bits[s_slot, s_row])
+        m = banks.meter
+        meter = dataclasses.replace(
+            m,
+            time_ns=m.time_ns + jnp.asarray(copy_plan.dt_slot),
+            e_act=m.e_act + jnp.asarray(copy_plan.e_act_slot),
+            e_pre=m.e_pre + jnp.asarray(copy_plan.e_pre_slot),
+            e_background=m.e_background
+            + jnp.asarray(copy_plan.dt_slot) * p_bg,
+            n_act=m.n_act + jnp.asarray(copy_plan.n_act_slot),
+            n_pre=m.n_pre + jnp.asarray(copy_plan.n_pre_slot),
+            n_aap=m.n_aap + jnp.asarray(copy_plan.n_aap_slot))
+        return dataclasses.replace(banks, bits=bits, meter=meter)
 
     def step(banks, credit, payloads):
         t0 = jnp.asarray(banks.meter.time_ns)
@@ -549,56 +613,39 @@ def _make_step_fn(cfg: DeviceConfig, runners, group_slots, bus_j,
                 new_banks = jax.tree_util.tree_map(
                     lambda full, upd: full.at[idx].set(upd), new_banks, out)
             reads.append(group_reads)   # batched: per-slot view sliced lazily
-        # In-slot execution excludes each slot's own bus occupancy and the
-        # drained copies (accounted by the contention model below).
-        exec_ns = jnp.asarray(new_banks.meter.time_ns) - t0 - bus_j_c
+        t1 = jnp.asarray(new_banks.meter.time_ns)      # before the drain
         if copy_plan is not None:
-            bits = new_banks.bits
-            si, sr, di, dr = copy_moves
-            if copy_independent:
-                # Independent copies (the common gather pattern: distinct
-                # destinations, none feeding a later copy) — ONE batched
-                # scatter instead of a row-at-a-time chain.
-                bits = bits.at[jnp.asarray(di), jnp.asarray(dr)].set(
-                    bits[jnp.asarray(si), jnp.asarray(sr)])
+            with jax.named_scope("pim.step.copy_drain"):
+                new_banks = drain(new_banks)
+        with jax.named_scope("pim.step.bus_fold"):
+            # In-slot execution excludes each slot's own bus occupancy and
+            # the drained copies (accounted by the contention model below).
+            exec_ns = t1 - t0 - bus_j_c
+            e1 = jnp.asarray(new_banks.meter.total_energy_nj)
+            compute_ns = jnp.max(exec_ns) + makespan
+            if async_host:
+                hidden = jnp.minimum(
+                    host_ch_c,
+                    jnp.maximum(jnp.asarray(credit, jnp.float32), 0.0))
             else:
-                for s_slot, s_row, d_slot, d_row in zip(si, sr, di, dr):
-                    bits = bits.at[d_slot, d_row].set(bits[s_slot, s_row])
-            m = new_banks.meter
-            meter = dataclasses.replace(
-                m,
-                time_ns=m.time_ns + jnp.asarray(copy_plan.dt_slot),
-                e_act=m.e_act + jnp.asarray(copy_plan.e_act_slot),
-                e_pre=m.e_pre + jnp.asarray(copy_plan.e_pre_slot),
-                e_background=m.e_background
-                + jnp.asarray(copy_plan.dt_slot) * p_bg,
-                n_act=m.n_act + jnp.asarray(copy_plan.n_act_slot),
-                n_pre=m.n_pre + jnp.asarray(copy_plan.n_pre_slot),
-                n_aap=m.n_aap + jnp.asarray(copy_plan.n_aap_slot))
-            new_banks = dataclasses.replace(new_banks, bits=bits,
-                                            meter=meter)
-        e1 = jnp.asarray(new_banks.meter.total_energy_nj)
-        compute_ns = jnp.max(exec_ns) + makespan
-        if async_host:
-            hidden = jnp.minimum(
-                host_ch_c,
-                jnp.maximum(jnp.asarray(credit, jnp.float32), 0.0))
-        else:
-            hidden = jnp.zeros_like(host_ch_c)
-        busy = busy0_c - hidden
-        wall = jnp.max(busy) + compute_ns
-        energy = jnp.sum(e1 - e0)
-        # The outgoing double-buffer credit: only an ASYNC step prefetches
-        # the next step's transfers under its compute window. A sync step
-        # resets the leaf to zero — its host engine ran synchronously, so
-        # there is nothing buffered for a later async step to hide behind.
-        credit_out = compute_ns if async_host else jnp.float32(0.0)
+                hidden = jnp.zeros_like(host_ch_c)
+            busy = busy0_c - hidden
+            wall = jnp.max(busy) + compute_ns
+            energy = jnp.sum(e1 - e0)
+            # The outgoing double-buffer credit: only an ASYNC step
+            # prefetches the next step's transfers under its compute
+            # window. A sync step resets the leaf to zero — its host engine
+            # ran synchronously, so there is nothing buffered for a later
+            # async step to hide behind.
+            credit_out = compute_ns if async_host else jnp.float32(0.0)
+            hidden_sum = jnp.sum(hidden)
         return (new_banks, tuple(reads), wall, energy, credit_out, busy,
-                jnp.sum(hidden))
+                hidden_sum)
 
     return jax.jit(step), step
 
 
+@_spanned("pim.sched.plan")
 def _plan_for(cfg: DeviceConfig, stripped, groups, deferred, *,
               use_kernels, interpret, refresh, async_host) -> _StepPlan:
     """Resolve (and cache) the step plan of one schedule layout."""
@@ -718,6 +765,7 @@ def _lower_step(cfg: DeviceConfig, programs):
     return flat, stripped, groups, deferred
 
 
+@_spanned("pim.sched.lower")
 def _lower_recurring(cfg: DeviceConfig, step_list, *, what: str, hint: str):
     """Lower a K-step RECURRING layout: step 0 fully, later steps only an
     O(slots) digest check — identical command streams imply identical copy
@@ -743,6 +791,7 @@ def _lower_recurring(cfg: DeviceConfig, step_list, *, what: str, hint: str):
     return flats, stripped0, groups0, deferred0
 
 
+@_spanned("pim.sched.schedule")
 def schedule(device: DeviceState,
              programs, *,
              use_kernels: bool | None = None,
@@ -777,20 +826,23 @@ def schedule(device: DeviceState,
     blocking device sync happens inside this call.
     """
     cfg = device.config
-    _, stripped, groups, deferred = _lower_step(cfg, programs)
+    with _span("pim.sched.lower"):
+        _, stripped, groups, deferred = _lower_step(cfg, programs)
     plan = _plan_for(cfg, stripped, groups, deferred,
                      use_kernels=use_kernels, interpret=interpret,
                      refresh=refresh, async_host=async_host)
     if verify:
         _verify_plans((plan,), "schedule layout")
-    payloads = tuple(
-        _payload_stack([stripped[k] for k in slots], cfg.words)
-        for slots in plan.group_slots)
+    with _span("pim.sched.payloads"):
+        payloads = tuple(
+            _payload_stack([stripped[k] for k in slots], cfg.words)
+            for slots in plan.group_slots)
     credit = device.host_credit_ns
     if not isinstance(credit, jax.Array):
         credit = jnp.float32(credit)
-    new_banks, greads, wall, energy, credit_out, busy, hidden_sum = plan.fn(
-        device.banks, credit, payloads)
+    with _span("pim.sched.dispatch"):
+        new_banks, greads, wall, energy, credit_out, busy, hidden_sum = \
+            plan.fn(device.banks, credit, payloads)
     SCHED_STATS["dispatches"] += 1
     stats = plan.copy.stats if plan.copy is not None else CopyDrainStats()
     return ScheduleResult(
@@ -867,6 +919,18 @@ class PipelineResult:
         return float(jnp.sum(jnp.asarray(self._host_overlap_ns)))
 
 
+@_spanned("pim.sched.payloads")
+def _step_xs(plan: _StepPlan, flats, words: int) -> tuple:
+    """The scan's payload xs of one recurring layout: per stream group, the
+    ``(K, n_group, n_payloads, words)`` stack of every step's HOSTW rows
+    (``flats`` holds each step's flat per-slot programs)."""
+    return tuple(
+        _stack_step_payloads(
+            [_payload_stack([flat[s] for s in slots], words)
+             for flat in flats])
+        for slots in plan.group_slots)
+
+
 def _stack_step_payloads(pay_list):
     """Stack per-step payload batches into the scan's ``(K, ...)`` xs. A
     fully-replicated pipeline (every step the same cached batch) reuses one
@@ -924,6 +988,7 @@ def _pipeline_fn(plan: _StepPlan, n_steps: int, donate: bool):
     return hit[0]
 
 
+@_spanned("pim.sched.pipeline")
 def schedule_pipeline(device: DeviceState, steps, *,
                       n_steps: int | None = None,
                       use_kernels: bool | None = None,
@@ -968,17 +1033,14 @@ def schedule_pipeline(device: DeviceState, steps, *,
                      refresh=refresh, async_host=async_host)
     if verify:
         _verify_plans((plan,), "pipeline layout")
-    xs = tuple(
-        _stack_step_payloads(
-            [_payload_stack([flats[k][s] for s in slots], cfg.words)
-             for k in range(len(step_list))])
-        for slots in plan.group_slots)
+    xs = _step_xs(plan, flats, cfg.words)
     credit = device.host_credit_ns
     if not isinstance(credit, jax.Array):
         credit = jnp.float32(credit)
-    fn = _pipeline_fn(plan, len(step_list), donate)
-    new_banks, credit_out, (reads, walls, energies, hidden) = fn(
-        device.banks, credit, xs)
+    with _span("pim.sched.dispatch"):
+        fn = _pipeline_fn(plan, len(step_list), donate)
+        new_banks, credit_out, (reads, walls, energies, hidden) = fn(
+            device.banks, credit, xs)
     SCHED_STATS["dispatches"] += 1
     stats = plan.copy.stats if plan.copy is not None else CopyDrainStats()
     return PipelineResult(
@@ -1301,8 +1363,9 @@ def _run_segmented(device: DeviceState, wplan: PipelinePlan, xs_phases,
     credit = device.host_credit_ns
     if not isinstance(credit, jax.Array):
         credit = jnp.float32(credit)
-    new_banks, credit_out, outs, boundary = fn(
-        device.banks, credit, xs_phases)
+    with _span("pim.sched.dispatch"):
+        new_banks, credit_out, outs, boundary = fn(
+            device.banks, credit, xs_phases)
     SCHED_STATS["dispatches"] += 1
     phase_results = tuple(
         _phase_result(cfg, plan, wplan.n_steps[p], walls, energies,
@@ -1317,6 +1380,7 @@ def _run_segmented(device: DeviceState, wplan: PipelinePlan, xs_phases,
         order=None)
 
 
+@_spanned("pim.sched.workload")
 def schedule_workload(device: DeviceState, phases, *,
                       order: Sequence[int] | None = None,
                       use_kernels: bool | None = None,
@@ -1424,13 +1488,10 @@ def schedule_workload(device: DeviceState, phases, *,
         _verify_plans(wplan.phases, "workload layout")
 
     if order is None:
-        xs_phases = tuple(
-            tuple(_stack_step_payloads(
-                [_payload_stack([flats[k][s] for s in slots], cfg.words)
-                 for k in range(n)])
-                for slots in plan.group_slots)
-            for plan, flats, n in zip(wplan.phases, flats_p, wplan.n_steps))
-        fn = _workload_fn(wplan, donate)
+        xs_phases = tuple(_step_xs(plan, flats, cfg.words)
+                          for plan, flats in zip(wplan.phases, flats_p))
+        with _span("pim.sched.dispatch"):
+            fn = _workload_fn(wplan, donate)
         if len(_workload_fast_cache) >= _WORKLOAD_FAST_CACHE_MAX:
             _workload_fast_cache.pop(next(iter(_workload_fast_cache)))
         _workload_fast_cache[fkey] = (
@@ -1461,22 +1522,24 @@ def schedule_workload(device: DeviceState, phases, *,
                 default=0)
     p_max = max((n for p in wplan.phases for n in p.group_n_payloads),
                 default=0)
-    pay = np.zeros((len(order), g_max, s_max, p_max, cfg.words),
-                   np.uint32)
-    cursor = [0] * n_ph
-    for t, pi in enumerate(order):
-        plan = wplan.phases[pi]
-        flat = flats_p[pi][cursor[pi]]
-        cursor[pi] += 1
-        for g, slots in enumerate(plan.group_slots):
-            for j, s in enumerate(slots):
-                for q, arr in enumerate(flat[s].payloads):
-                    pay[t, g, j, q] = np.asarray(arr, np.uint32)
+    with _span("pim.sched.payloads"):
+        pay = np.zeros((len(order), g_max, s_max, p_max, cfg.words),
+                       np.uint32)
+        cursor = [0] * n_ph
+        for t, pi in enumerate(order):
+            plan = wplan.phases[pi]
+            flat = flats_p[pi][cursor[pi]]
+            cursor[pi] += 1
+            for g, slots in enumerate(plan.group_slots):
+                for j, s in enumerate(slots):
+                    for q, arr in enumerate(flat[s].payloads):
+                        pay[t, g, j, q] = np.asarray(arr, np.uint32)
 
-    fn = _switch_fn(wplan, cfg.words, donate)
-    new_banks, credit_out, (fr, walls, energies, hidden, credits) = fn(
-        device.banks, credit,
-        jnp.asarray(np.asarray(order, np.int32)), jnp.asarray(pay))
+    with _span("pim.sched.dispatch"):
+        fn = _switch_fn(wplan, cfg.words, donate)
+        new_banks, credit_out, (fr, walls, energies, hidden, credits) = fn(
+            device.banks, credit,
+            jnp.asarray(np.asarray(order, np.int32)), jnp.asarray(pay))
     SCHED_STATS["dispatches"] += 1
     phase_results = []
     for p, plan in enumerate(wplan.phases):

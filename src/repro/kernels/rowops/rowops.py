@@ -14,6 +14,10 @@ Two execution styles:
     carry iteration runs on a VMEM-resident block, eliminating 3·(w-1)
     intermediate row round-trips (quantified in EXPERIMENTS.md §Perf).
 
+Each ``pallas_call`` is named (``rowops_shift_cols``, ``rowops_bitwise_<op>``,
+``rowops_ripple_add``), and the name is the kernel's in the compiled program
+and the device trace.
+
 Block shapes: rows are tiled (block_rows, W) — a full row of W words stays
 contiguous in the block so the carry network never crosses a block boundary;
 block_rows × W × 4 B must fit VMEM (default 8 × 2048 × 4 = 64 KiB).
@@ -131,6 +135,7 @@ def bitwise(a, b=None, c=None, *, op: str,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(a.shape, jnp.uint32),
         interpret=interpret,
+        name=f"rowops_bitwise_{op}",
     )(*args)
 
 
@@ -145,6 +150,7 @@ def shift_cols(x, k: int, *, block_rows: int = DEFAULT_BLOCK_ROWS,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.uint32),
         interpret=interpret,
+        name="rowops_shift_cols",
     )(x)
 
 
@@ -161,4 +167,5 @@ def ripple_add(a, b, *, width: int, block_rows: int = DEFAULT_BLOCK_ROWS,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(a.shape, jnp.uint32),
         interpret=interpret,
+        name="rowops_ripple_add",
     )(a, b)

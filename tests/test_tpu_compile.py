@@ -99,3 +99,37 @@ def test_step_runner_compiles_for_64_slots(one_chip):
         banks, payloads).compile()
     _assert_kernel(compiled)
     assert compiled.memory_analysis().argument_size_in_bytes < HBM_BYTES
+
+
+def test_compiled_runner_keeps_scopes_and_kernel_names(one_chip):
+    """The names the device trace is read by survive the chip's compiler:
+    the runner's named scopes as ``op_name`` metadata (the row math's
+    around its kernel) and the ``rowops_*`` kernel names."""
+    b = pim.ProgramBuilder(ROWS, WORDS)
+    b.issue()
+    b.write_row(0, np.zeros(WORDS, np.uint32))
+    b.shift_k(0, 1, 512)
+    b.ambit_xor(0, 1, 2)
+    b.read_row(2)
+    runner = pim.make_runner(pim.compile_program(b.build()),
+                             use_kernels=True, interpret=False,
+                             payload_arg=True)
+    cfg = pim.paper_device(1, subarrays=2)
+    banks = _sds(jax.eval_shape(lambda: pim.make_device(cfg).banks),
+                 one_chip)
+    payloads = jax.ShapeDtypeStruct((cfg.n_slots, 1, WORDS), jnp.uint32,
+                                    sharding=one_chip)
+    text = jax.jit(jax.vmap(runner.traced)).lower(
+        banks, payloads).compile().as_text()
+    for scope in ("pim.runner.row_math", "pim.runner.host_io",
+                  "pim.runner.meter_fold"):
+        assert f"/{scope}/" in text, scope
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("rowops_shift_cols", "rowops_bitwise_maj",
+                 "rowops_bitwise_not"):
+        assert any(f"%{name}" in k and "/pim.runner.row_math/" in k
+                   for k in kernels), name
+    x = _rows(8, one_chip)
+    assert "%rowops_ripple_add" in kops.ripple_add.lower(
+        x, x, width=8, interpret=False).compile().as_text()
