@@ -68,7 +68,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import hashlib
-from typing import Callable, Sequence
+from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -180,10 +180,12 @@ def stream_key(p: PimProgram):
 #                    (the acceptance bar is <= 1 per steady-state step)
 #   plan_misses    — step-plan cache misses (a new schedule layout)
 #   compile_misses — _compiled_for cache misses (a new program stream)
-#   upload_bytes   — bytes of HOSTW payload stacks _payload_stack put on the
-#                    device (payload-cache misses only)
+#   upload_bytes   — bytes of HOSTW payload stacks put on the device
+#                    (payload-cache misses only)
+#   payload_hits   — payload-cache lookups that hit, one per stream group
+#   payload_misses — ... and that missed (each uploads its group's rows)
 SCHED_STATS = {"dispatches": 0, "plan_misses": 0, "compile_misses": 0,
-               "upload_bytes": 0}
+               "upload_bytes": 0, "payload_hits": 0, "payload_misses": 0}
 
 
 # Host spans, written into the profiler's trace beside the device events
@@ -246,13 +248,19 @@ def compiled_for(program: PimProgram,
 
 # Stacked payload batches keyed on the *identity* of the payload arrays:
 # recurring flushes (PimVM pipelines) schedule the same PimProgram objects
-# over and over, and re-np.stack-ing identical host data plus re-uploading
-# it to the device every step was pure waste. Cache values hold references
-# to the source arrays, pinning their ids for the lifetime of the entry
-# (so a recycled id can never alias a dead key). Bounded by entry count
-# AND by pinned bytes: the "multi" pipeline entries hold K-times-stacked
-# device arrays, and a long-running serving loop with churning payloads
-# would otherwise grow device memory without bound.
+# over and over, and re-stacking identical host data plus re-uploading it
+# to the device every step was pure waste. Entries pin the source arrays
+# their keys name (so a recycled id can never alias a dead key) and carry
+# their byte count, taken once at put. Bounded by entry count AND by
+# pinned bytes: pipeline entries hold K-step device arrays, and a
+# long-running serving loop with churning payloads would otherwise grow
+# device memory without bound.
+class _PayloadEntry(NamedTuple):
+    array: jax.Array        # what a lookup of the key returns
+    refs: tuple             # the source arrays the key names, pinned
+    nbytes: int             # array and refs, counted at put
+
+
 _payload_cache: dict = {}
 _PAYLOAD_CACHE_MAX = 256
 _PAYLOAD_CACHE_MAX_BYTES = 256 << 20        # pinned stacked-array budget
@@ -260,14 +268,12 @@ _payload_cache_bytes = 0
 
 
 def _entry_nbytes(hit) -> int:
-    """Bytes one cache entry pins: the stacked device array plus the host
-    source arrays it keeps alive for id stability."""
+    """Bytes one cache entry pins: the stacked device array plus the tuple
+    of source arrays it keeps alive for id stability. Reads ``nbytes``
+    attributes only: iterating a device array would slice it row by row on
+    the device."""
     stacked, refs = hit
-    n = int(stacked.nbytes)
-    for group in refs:
-        arrays = group if isinstance(group, (tuple, list)) else (group,)
-        n += sum(int(a.nbytes) for a in arrays)  # no host sync: attr only
-    return n
+    return int(stacked.nbytes) + sum([int(a.nbytes) for a in refs])
 
 
 def _payload_cache_get(key):
@@ -278,20 +284,22 @@ def _payload_cache_get(key):
     return hit
 
 
-def _payload_cache_put(key, hit) -> None:
+def _payload_cache_put(key, array, refs: tuple) -> None:
     """Insert at the MRU end, then evict LRU entries until both the entry
     count and the pinned-byte budget hold. The newest entry itself is never
     evicted — one oversized batch must still be cacheable or recurring
-    pipelines would re-upload it every call."""
+    pipelines would re-upload it every call. O(1) per eviction: an entry's
+    byte count is stored with it."""
     global _payload_cache_bytes
-    _payload_cache[key] = hit
-    _payload_cache_bytes += _entry_nbytes(hit)
+    entry = _PayloadEntry(array, refs, _entry_nbytes((array, refs)))
+    _payload_cache[key] = entry
+    _payload_cache_bytes += entry.nbytes
     while (len(_payload_cache) > _PAYLOAD_CACHE_MAX
            or _payload_cache_bytes > _PAYLOAD_CACHE_MAX_BYTES):
         if len(_payload_cache) <= 1:
             break
-        old = _payload_cache.pop(next(iter(_payload_cache)))
-        _payload_cache_bytes -= _entry_nbytes(old)
+        _payload_cache_bytes -= _payload_cache.pop(
+            next(iter(_payload_cache))).nbytes
 
 
 def _payload_cache_clear() -> None:
@@ -301,29 +309,61 @@ def _payload_cache_clear() -> None:
     _payload_cache_bytes = 0
 
 
-def _payload_stack(programs: Sequence[PimProgram], words: int) -> jnp.ndarray:
-    """(n_slots_in_group, n_payloads, words) uint32 HOSTW payload batch."""
-    n_pay = len(programs[0].payloads)
-    if n_pay == 0:
-        key = ("zeros", len(programs), words)
-    else:
-        # shape prefix disambiguates the partitioning: the same id sequence
-        # could otherwise alias e.g. 2 programs x 2 payloads vs 4 x 1
-        key = (len(programs), n_pay, words) + tuple(
-            id(a) for p in programs for a in p.payloads)
+def _payload_lookup(key, refs: tuple,
+                    build: Callable[[], jax.Array]) -> jax.Array:
+    """The device array cached under ``key``; on a miss ``build()``'s,
+    cached pinning ``refs``. Counts one payload-cache hit or miss."""
     hit = _payload_cache_get(key)
-    if hit is None:
-        if n_pay == 0:
-            stacked = jnp.zeros((len(programs), 0, words), jnp.uint32)
-            refs = ()
-        else:
-            stacked = jnp.asarray(np.stack(
-                [np.stack(p.payloads) for p in programs]).astype(np.uint32))
-            refs = tuple(p.payloads for p in programs)
-            SCHED_STATS["upload_bytes"] += int(stacked.nbytes)
-        _payload_cache_put(key, (stacked, refs))
-        return stacked
-    return hit[0]
+    if hit is not None:
+        SCHED_STATS["payload_hits"] += 1
+        return hit.array
+    SCHED_STATS["payload_misses"] += 1
+    array = build()
+    _payload_cache_put(key, array, refs)
+    return array
+
+
+def _upload(host: np.ndarray) -> jax.Array:
+    """One host-to-device copy, counted in ``upload_bytes``."""
+    SCHED_STATS["upload_bytes"] += host.nbytes
+    return jax.device_put(host)
+
+
+def _group_payloads(batches, words: int, k_axis: bool) -> jax.Array:
+    """One stream group's HOSTW payloads on the device; ``batches`` holds
+    each step's programs of the group. ``(K, n_group, n_payloads, words)``
+    uint32 with ``k_axis``, else the one batch's ``(n_group, n_payloads,
+    words)``. A miss stacks every row in one host pass and uploads the
+    result once; a batch that all K > 1 steps share is uploaded once and
+    replicated on the device."""
+    K, n, n_pay = len(batches), len(batches[0]), len(batches[0][0].payloads)
+    shape = ((K,) if k_axis else ()) + (n, n_pay, words)
+    if n_pay == 0:
+        return _payload_lookup(("zeros",) + shape, (),
+                               lambda: jnp.zeros(shape, jnp.uint32))
+    refs = tuple([a for progs in batches for p in progs for a in p.payloads])
+    ids = tuple(map(id, refs))
+    per = n * n_pay                     # rows a step
+    replicated = K > 1 and all(ids[k * per:(k + 1) * per] == ids[:per]
+                               for k in range(1, K))
+    if replicated:
+        refs, ids = refs[:per], ids[:per]
+    # the shape prefix disambiguates the partitioning: the same id sequence
+    # could otherwise alias e.g. 2 programs x 2 payloads vs 4 x 1
+    tag = "steps" if replicated else "multi" if K > 1 else "batch"
+    key = (tag,) + shape + ids
+
+    def build():
+        host = np.asarray(np.array(refs), np.uint32)   # no copy if uint32
+        if replicated:
+            return jnp.stack([_upload(host.reshape(shape[1:]))] * K)
+        return _upload(host.reshape(shape))
+    return _payload_lookup(key, refs, build)
+
+
+def _payload_stack(programs: Sequence[PimProgram], words: int) -> jax.Array:
+    """(n_slots_in_group, n_payloads, words) uint32 HOSTW payload batch."""
+    return _group_payloads([programs], words, k_axis=False)
 
 
 def _normalize_programs(cfg: DeviceConfig, programs) -> list:
@@ -925,32 +965,9 @@ def _step_xs(plan: _StepPlan, flats, words: int) -> tuple:
     ``(K, n_group, n_payloads, words)`` stack of every step's HOSTW rows
     (``flats`` holds each step's flat per-slot programs)."""
     return tuple(
-        _stack_step_payloads(
-            [_payload_stack([flat[s] for s in slots], words)
-             for flat in flats])
+        _group_payloads([[flat[s] for s in slots] for flat in flats], words,
+                        k_axis=True)
         for slots in plan.group_slots)
-
-
-def _stack_step_payloads(pay_list):
-    """Stack per-step payload batches into the scan's ``(K, ...)`` xs. A
-    fully-replicated pipeline (every step the same cached batch) reuses one
-    stacked device array via the payload cache instead of re-uploading K
-    copies of identical host data per call."""
-    if any(p is not pay_list[0] for p in pay_list):
-        key = ("multi",) + tuple(id(p) for p in pay_list)
-        hit = _payload_cache_get(key)
-        if hit is None:
-            # the cache entry holds the batches, pinning their ids
-            hit = (jnp.stack(pay_list), tuple(pay_list))
-            _payload_cache_put(key, hit)
-        return hit[0]
-    key = ("steps", len(pay_list), id(pay_list[0]))
-    hit = _payload_cache_get(key)
-    if hit is None:
-        # the cache entry holds the source batch, pinning its id
-        hit = (jnp.stack([pay_list[0]] * len(pay_list)), pay_list[0])
-        _payload_cache_put(key, hit)
-    return hit[0]
 
 
 _pipeline_cache: dict = {}

@@ -15,12 +15,15 @@ def _fresh_pim_stats():
     Also drop the scheduler caches: each cached plan keeps a compiled
     program alive, and one worker running many tests would otherwise
     gather them until the process runs out of memory mappings and the next
-    XLA compile crashes it."""
+    XLA compile crashes it. Drop them again after the test: a worker may
+    next run a test of ``bench/tests``, which compiles every pipeline the
+    scheduler's cache holds for its own device geometry."""
     import repro.core.pim as pim
 
     pim.reset_stats()
     pim.clear_caches()
     yield
+    pim.clear_caches()
 
 try:  # hypothesis is optional: clean environments still run the example tests
     from hypothesis import settings, HealthCheck

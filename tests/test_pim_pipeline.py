@@ -8,6 +8,7 @@ XLA dispatch per step), the payload-stack cache, and the ``lax.scan``
 pipeline APIs (``schedule_pipeline`` / ``PimVM.run_pipeline``) being
 bit-exact against the per-step path.
 """
+import dataclasses
 import importlib
 
 import numpy as np
@@ -298,7 +299,7 @@ def test_payload_cache_byte_budget_evicts_pinned_arrays(monkeypatch):
     probe = batch()
     per_entry = pim_schedule._entry_nbytes(
         (pim_schedule._payload_stack(probe, WORDS),
-         tuple(p.payloads for p in probe)))
+         tuple(a for p in probe for a in p.payloads)))
     pim_schedule._payload_cache_clear()
     monkeypatch.setattr(pim_schedule, "_PAYLOAD_CACHE_MAX_BYTES",
                         3 * per_entry)
@@ -372,6 +373,171 @@ def test_payload_cache_id_recycling_never_aliases(monkeypatch):
     out = pim_schedule._payload_stack([recycled], WORDS)
     np.testing.assert_array_equal(np.asarray(out[0, 0]),
                                   recycled.payloads[0])
+
+
+# -- the payload path: one host pass, one upload, stored byte counts --------
+
+def _old_batch(progs, words=WORDS):
+    """The payload batch as per-program ``np.stack`` calls built it."""
+    if not progs[0].payloads:
+        return jnp.zeros((len(progs), 0, words), jnp.uint32)
+    return jnp.asarray(np.stack(
+        [np.stack(p.payloads) for p in progs]).astype(np.uint32))
+
+
+def _old_xs(batches, words=WORDS):
+    """The scan's xs as a device ``jnp.stack`` of per-step batches built
+    it."""
+    return jnp.stack([_old_batch(b, words) for b in batches])
+
+
+def _raw_prog(payloads):
+    """A program whose payloads are kept as given (``with_payloads`` and
+    the builder would convert them to uint32)."""
+    b = pim.ProgramBuilder(ROWS, WORDS)
+    b.issue()
+    for i in range(len(payloads)):
+        b.write_row(i, np.zeros(WORDS, np.uint32))
+    b.read_row(0)
+    return dataclasses.replace(b.build(), payloads=tuple(payloads))
+
+
+def _payload_case(case, rng):
+    """Per-step program lists of one stream group, for ``case``."""
+    n = 3
+
+    def fresh(n_pay=1, dtype=np.uint32):
+        if dtype == np.uint32:
+            return [_raw_prog([_rand_row(rng) for _ in range(n_pay)])
+                    for _ in range(n)]
+        info = np.iinfo(dtype)
+        return [_raw_prog([rng.integers(info.min, info.max, WORDS,
+                                        dtype=dtype, endpoint=True)
+                           for _ in range(n_pay)]) for _ in range(n)]
+    if case == "k1":
+        return [fresh()]
+    if case == "k3_same":
+        progs = fresh()
+        return [progs, progs, progs]
+    if case == "k3_distinct":
+        return [fresh() for _ in range(3)]
+    if case == "k3_partly_repeated":
+        a, b = fresh(), fresh()
+        return [a, b, a]
+    if case == "no_payloads":
+        return [fresh(0), fresh(0)]
+    if case == "two_payloads":
+        return [fresh(2), fresh(2)]
+    if case == "int64":
+        return [fresh(1, np.int64), fresh(1, np.int64)]
+    if case == "uint8_and_int64_mixed":
+        a = fresh(2, np.uint8)
+        return [[_raw_prog([a[0].payloads[0],
+                            rng.integers(-2**40, 2**40, WORDS)])] + a[1:]]
+    raise AssertionError(case)
+
+
+PAYLOAD_CASES = ["k1", "k3_same", "k3_distinct", "k3_partly_repeated",
+                 "no_payloads", "two_payloads", "int64",
+                 "uint8_and_int64_mixed"]
+
+
+@pytest.mark.parametrize("case", PAYLOAD_CASES)
+def test_payload_xs_bit_equal_to_the_old_stacks(case):
+    """The one-pass xs (and schedule()'s batch) equal, bit for bit and in
+    dtype and shape, what the per-program np.stack / device jnp.stack path
+    gave, on a miss and on the hit that follows."""
+    batches = _payload_case(case, np.random.default_rng(30))
+    want = np.asarray(_old_xs(batches))
+    for _ in range(2):                          # the miss, then the hit
+        got = pim_schedule._group_payloads(batches, WORDS, k_axis=True)
+        assert got.dtype == jnp.uint32 and got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got), want)
+        one = pim_schedule._payload_stack(batches[0], WORDS)
+        assert one.dtype == jnp.uint32 and one.shape == want.shape[1:]
+        np.testing.assert_array_equal(np.asarray(one), want[0])
+
+
+def _assert_byte_books():
+    """The running total is the sum of the stored counts, and each stored
+    count is the bytes its entry pins."""
+    entries = list(pim_schedule._payload_cache.values())
+    assert pim_schedule._payload_cache_bytes == sum(e.nbytes
+                                                    for e in entries)
+    for e in entries:
+        assert e.nbytes == e.array.nbytes + sum(a.nbytes for a in e.refs)
+        assert e.nbytes == pim_schedule._entry_nbytes((e.array, e.refs))
+
+
+def test_payload_cache_bytes_are_the_stored_counts(monkeypatch):
+    """After any sequence of puts, hits and evictions (by count and by
+    bytes, over every kind of entry), the byte total equals the sum of the
+    stored per-entry counts, each the nbytes of what its entry pins, and
+    every entry pins the arrays its key names."""
+    rng = np.random.default_rng(31)
+    per_batch = 2 * 3 * WORDS * 4          # device batch + its host rows
+    monkeypatch.setattr(pim_schedule, "_PAYLOAD_CACHE_MAX", 5)
+    monkeypatch.setattr(pim_schedule, "_PAYLOAD_CACHE_MAX_BYTES",
+                        7 * per_batch)
+    seen = []
+    for i in range(60):
+        if seen and rng.random() < 0.3:
+            batches, k_axis = seen[int(rng.integers(len(seen)))]
+        else:
+            case = PAYLOAD_CASES[int(rng.integers(len(PAYLOAD_CASES)))]
+            batches, k_axis = _payload_case(case, rng), bool(i % 2)
+            seen.append((batches, k_axis))
+        if k_axis:
+            pim_schedule._group_payloads(batches, WORDS, k_axis=True)
+        else:
+            pim_schedule._payload_stack(batches[0], WORDS)
+        _assert_byte_books()
+        assert len(pim_schedule._payload_cache) <= 5
+        assert (pim_schedule._payload_cache_bytes <= 7 * per_batch
+                or len(pim_schedule._payload_cache) == 1)
+        for key, e in pim_schedule._payload_cache.items():
+            ref_ids = {id(a) for a in e.refs}
+            assert all(k in ref_ids for k in key
+                       if isinstance(k, int) and k > 1 << 16)
+    assert pim_schedule.SCHED_STATS["payload_misses"] > 5  # evictions ran
+
+
+def test_payload_cache_put_and_evict_never_touch_a_device_array(
+        monkeypatch):
+    """Putting or evicting an entry whose refs hold a device array reads
+    attributes only: no device dispatch, no transfer, no jaxpr trace."""
+    from jax._src import array as jax_array
+
+    stacked = jax.device_put(np.ones((1, 4, 1, WORDS), np.uint32))
+    batch = jax.device_put(np.ones((4, 1, WORDS), np.uint32))
+    jax.block_until_ready((stacked, batch))
+    jax.clear_caches()              # so any eager op would trace anew
+    traces = []
+
+    def hear(event, duration, **kw):
+        if event == "/jax/core/compile/jaxpr_trace_duration":
+            traces.append(kw.get("fun_name"))
+
+    def refuse(*a, **kw):
+        raise AssertionError("touched a device array")
+
+    for name in ("__iter__", "__getitem__", "__array__", "_unstack",
+                 "_chunk_iter", "_value"):
+        monkeypatch.setattr(jax_array.ArrayImpl, name, property(refuse)
+                            if name == "_value" else refuse)
+    monkeypatch.setattr(pim_schedule, "_PAYLOAD_CACHE_MAX", 1)
+    jax.monitoring.register_event_duration_secs_listener(hear)
+    try:
+        with jax.transfer_guard("disallow"):
+            assert (pim_schedule._entry_nbytes((stacked, (batch,)))
+                    == stacked.nbytes + batch.nbytes)
+            pim_schedule._payload_cache_put(("steps", 1), stacked, (batch,))
+            pim_schedule._payload_cache_put(("steps", 2), stacked, (batch,))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(hear)
+    assert list(pim_schedule._payload_cache) == [("steps", 2)]   # evicted
+    _assert_byte_books()
+    assert traces == []
 
 
 def test_workload_fast_cache_pins_key_steps():

@@ -74,6 +74,73 @@ def test_upload_bytes_zero_without_payloads():
     assert pim_schedule.SCHED_STATS["upload_bytes"] == 0
 
 
+def _two_groups(cfg, seed):
+    """A fresh layout of two stream groups (shift by 40 and by 3)."""
+    rng = np.random.default_rng(seed)
+    return [_prog(rng.integers(0, 2**32, WORDS, dtype=np.uint32),
+                  k=40 if s % 2 else 3) for s in range(cfg.n_slots)]
+
+
+_CALLS = {
+    # name: (call on layouts made by `new`, steps whose rows are uploaded)
+    "schedule": (lambda dev, new: pim.schedule(dev, new()), 1),
+    "pipeline_k1": (lambda dev, new: pim.schedule_pipeline(dev, [new()]), 1),
+    "pipeline_replicated": (
+        lambda dev, new: pim.schedule_pipeline(dev, new(), n_steps=3), 1),
+    "pipeline_distinct": (
+        lambda dev, new: pim.schedule_pipeline(dev, [new(), new(), new()]),
+        3),
+    "workload": (lambda dev, new: pim.schedule_workload(
+        dev, [[new()], [new(), new()]]), 3),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_CALLS))
+def test_payload_hits_misses_and_upload_bytes_exact(kind):
+    """One payload-cache lookup per stream group (and phase): fresh rows
+    miss and upload each distinct step's rows once; the same programs
+    again hit and upload nothing."""
+    cfg = _cfg()
+    dev = pim.make_device(cfg)
+    call, n_up = _CALLS[kind]
+    lookups = 2 * (2 if kind == "workload" else 1)
+    seeds = iter(range(100, 200))
+    made = []
+
+    def new():
+        made.append(_two_groups(cfg, next(seeds)))
+        return made[-1]
+
+    def again():                        # the same objects, in order
+        return made.pop(0)
+
+    stats = pim_schedule.SCHED_STATS
+    call(dev, new)
+    assert (stats["payload_hits"], stats["payload_misses"]) == (0, lookups)
+    assert stats["upload_bytes"] == _upload(cfg, n_up)
+    call(dev, again)
+    assert (stats["payload_hits"], stats["payload_misses"]) == (lookups,
+                                                                lookups)
+    assert stats["upload_bytes"] == _upload(cfg, n_up)
+    call(dev, new)
+    assert (stats["payload_hits"], stats["payload_misses"]) == (
+        lookups, 2 * lookups)
+    assert stats["upload_bytes"] == _upload(cfg, 2 * n_up)
+
+
+def test_payload_lookups_without_payloads_upload_nothing():
+    cfg = _cfg()
+    b = pim.ProgramBuilder(ROWS, WORDS)
+    b.issue()
+    b.shift_k(0, 1, 3)
+    dev = pim.make_device(cfg)
+    pim.schedule_pipeline(dev, [b.build()] * cfg.n_slots, n_steps=2)
+    pim.schedule_pipeline(dev, [b.build()] * cfg.n_slots, n_steps=2)
+    stats = pim_schedule.SCHED_STATS
+    assert (stats["payload_hits"], stats["payload_misses"]) == (1, 1)
+    assert stats["upload_bytes"] == 0
+
+
 # -- host spans ---------------------------------------------------------------
 
 def _spans(tmp_path, fn):
