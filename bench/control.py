@@ -4,13 +4,13 @@ the control's on a few, at the cell's own size, in one process.
     python3 bench/control.py --workload <cell> --seeds 1,2,... \\
         --control-seeds 101,102,103 [--out readings.json]
 
-For each seed the program runs the same jobs as a run's check compares
-(the first ``check_jobs`` of that seed) through the timed path, and the
+For each seed the program runs the first ``check_jobs`` jobs of a window
+of that seed through the timed path, after the run's own set-up, and the
 numbers are compared with the plain reference. For each control seed the
-reference computed in bfloat16 stands in for the program: the precision
-below the float32 the configuration states for its meters. A limit lies
-above every program reading and below every control reading. The
-benchmark's own runs never run this.
+reference computed in bfloat16 stands in for the program on the jobs the
+program ran first: the precision below the float32 the configuration
+states for its meters. A limit lies above every program reading and below
+every control reading. The benchmark's own runs never run this.
 """
 from __future__ import annotations
 
@@ -44,34 +44,23 @@ def main(argv=None) -> int:
     spec = harness.Spec.load(a.workload)
     m = int(spec.traffic["check_jobs"])
     span = (lambda name: contextlib.nullcontext())
-    device0 = None
     rows = []
 
-    def cell_for(seed):
-        nonlocal device0
+    def first_jobs(seed):
         cell = spec.make_cell(pim, seed)
-        if device0 is None:
-            cell.make_device()
-            device0 = cell.device0
-        cell.device0 = device0
-        return cell
+        kept = harness.first_jobs(cell, m, span)
+        cell.release()
+        return cell, kept
 
     for seed in [int(s) for s in a.seeds.split(",") if s]:
-        cell = cell_for(seed)
-        kept = []
         t0 = time.perf_counter()
-        for i in range(m):
-            inp = cell.inputs(i)
-            out = cell.run(inp, span)
-            harness.fetch_state(out)
-            kept.append((inp, out))
+        cell, kept = first_jobs(seed)
         nums = harness.check(cell, kept, spec.traffic["limits"])
         rows.append({"kind": "program", "seed": seed, "numbers": nums,
                      "seconds": time.perf_counter() - t0})
         print(json.dumps(rows[-1]), flush=True)
     for seed in [int(s) for s in a.control_seeds.split(",") if s]:
-        cell = spec.make_cell(pim, seed)
-        kept = [(cell.inputs(i), None) for i in range(m)]
+        cell, kept = first_jobs(seed)
         nums = harness.check(cell, kept, spec.traffic["limits"],
                              control=ml_dtypes.bfloat16)
         rows.append({"kind": "control_bf16", "seed": seed, "numbers": nums})

@@ -9,7 +9,9 @@ its own, found by the names in ``BENCHMARK.json``:
   bench/traffic/<traffic>.json  the traffic's parameters; may name its own
                                 ``family``
   bench/families/<family>.py    ``Cell``: job inputs from the seed, the
-                                timed path, and the plain reference
+                                timed path (``serve``: the client jobs
+                                each call finishes), and the plain
+                                reference
   bench/metrics/<metric>.py     ``value(run)``: one metric from a finished
                                 window, or None where it finds nothing
 """
@@ -99,11 +101,25 @@ class Spec:
         return fam.Cell(pim, self.config, self.traffic, seed)
 
 
+@dataclasses.dataclass
+class Done:
+    """One client job that a call of ``JobCell.serve`` finished."""
+
+    t_submit: float         # host clock: its inputs handed over
+    t_done: float           # host clock: its reads and meters on the host
+    commands: int           # simulated commands, from the reference's lists
+    inp: object             # what the reference runs
+    out: dict               # what the check compares
+
+
 class JobCell:
     """Base of a family's ``Cell``. A job is one user submission: fresh
     inputs from the seed handed to the entry point, run until its reads and
-    meters are on the host. Subclasses set ``commands_per_job`` and define
-    ``inputs(i)``, ``run(inputs, span)`` and ``reference(inputs, dtype)``."""
+    meters are on the host. Subclasses define ``inputs(i)``, the timed path
+    and ``reference(inputs, dtype)``. A single-client family sets
+    ``commands_per_job`` and defines ``run(inputs, span)``, one job a call;
+    a family of several clients overrides ``serve``, ``warm_up`` and
+    ``release`` and may add ``counters``."""
 
     commands_per_job = 0
 
@@ -123,6 +139,40 @@ class JobCell:
         self.device0 = self.pim.make_device(self.cfg)
         self.jax.block_until_ready(self.device0.banks)
 
+    def release(self) -> None:
+        """Free the program's device state before the reference runs."""
+        self.device0 = None
+
+    def serve(self, inp, span) -> list:
+        """One call of the timed path: the client jobs it finished, as
+        ``Done`` records. Here one job a call, timed from the hand-off of
+        its inputs until its reads and meters are on the host."""
+        t0 = time.perf_counter()
+        out = self.run(inp, span)
+        t1 = time.perf_counter()
+        return [Done(t0, t1, self.commands_per_job, inp, out)]
+
+    def warm_up(self, span) -> float:
+        """Compile and warm what the window runs. Returns the host seconds
+        of one warm call of ``serve``, which sizes the inputs made ahead.
+        Here two jobs with inputs of their own: the first compiles; the
+        second times a warm job."""
+        warm = []
+        for i in WARM:
+            inp = self.inputs(i)
+            t0 = time.perf_counter()
+            done = self.serve(inp, span)
+            warm.append(time.perf_counter() - t0)
+            del done
+        log(f"warm-up jobs {warm[0]:.3f} s (first, compiles) and "
+            f"{warm[1]:.6f} s")
+        return warm[1]
+
+    def counters(self) -> dict:
+        """The family's own cumulative counters; the window reports their
+        growth beside the program's."""
+        return {}
+
     @staticmethod
     def meter_dict(meter) -> dict:
         return {f.name: np.asarray(getattr(meter, f.name))
@@ -130,6 +180,14 @@ class JobCell:
 
     def paper_line(self, out) -> str | None:
         return None
+
+    def control_output(self, ref: dict) -> dict:
+        """The reference's own results in the shape of a job's output: the
+        control puts a lower-precision reference in the program's place."""
+        dev = ref["dev"]
+        return {"reads": ref["reads"], "meter": dev.meter(),
+                "wall": ref["wall"], "energy": ref["energy"],
+                "state_host": {k: getattr(dev, k) for k in STATE}}
 
     def compare(self, inp, out, ref) -> dict:
         """The numbers compared for one job, each against its limit."""
@@ -160,13 +218,21 @@ def fetch_state(out: dict) -> None:
     out["state_host"] = {k: np.asarray(getattr(s, k)) for k in STATE}
 
 
-def control_output(ref: dict) -> dict:
-    """The reference's own results in the shape of a job's output: the
-    control puts a lower-precision reference in the program's place."""
-    dev = ref["dev"]
-    return {"reads": ref["reads"], "meter": dev.meter(), "wall": ref["wall"],
-            "energy": ref["energy"],
-            "state_host": {k: getattr(dev, k) for k in STATE}}
+def first_jobs(cell: JobCell, n: int, span) -> list:
+    """The first ``n`` jobs of a window, as ``(inputs, output)`` with their
+    states on the host: the cell set up and warmed as a run does it, then
+    its timed path."""
+    cell.make_device()
+    cell.warm_up(span)
+    kept: list = []
+    i = 0
+    while len(kept) < n:
+        kept.extend((d.inp, d.out) for d in cell.serve(cell.inputs(i), span))
+        i += 1
+    kept = kept[:n]
+    for _, out in kept:
+        fetch_state(out)
+    return kept
 
 
 def check(cell: JobCell, kept, limits: dict, control=None) -> dict:
@@ -177,7 +243,7 @@ def check(cell: JobCell, kept, limits: dict, control=None) -> dict:
     for inp, out in kept:
         ref = cell.reference(inp, np.float64)
         if control is not None:
-            out = control_output(cell.reference(inp, control))
+            out = cell.control_output(cell.reference(inp, control))
         nums = cell.compare(inp, out, ref)
         for k, v in nums.items():
             if k in total and k.endswith("_err"):
@@ -213,10 +279,12 @@ class Run:
     """What a finished window gives the metric readers."""
 
     seconds: float                  # window length, host clock
-    jobs: list                      # (start, end) of every completed job
+    jobs: list                      # (submit, done) of every client job
+                                    # completed in the window
     commands: int                   # simulated commands of those jobs
     setup_s: float
-    counters: dict                  # program counters' growth in the window
+    counters: dict                  # program and family counters' growth
+                                    # in the window
     compiles: int                   # JAX backend compiles in the window
     trace: object = None            # tracered.Reduction of a traced window
 
@@ -245,13 +313,14 @@ class _Compiles:
             self.traces[name] = self.traces.get(name, 0) + 1
 
 
-def counters() -> dict:
-    """The program's own counters: scheduler dispatches and cache misses,
-    runner traces."""
+def counters(cell: JobCell) -> dict:
+    """The program's own counters (scheduler dispatches and cache misses,
+    runner traces) and the family's."""
     sched = importlib.import_module("repro.core.pim.schedule")
     runner = importlib.import_module("repro.core.pim.exec")
     c = {f"sched.{k}": v for k, v in sched.SCHED_STATS.items()}
     c.update({f"runner.{k}": v for k, v in runner.RUNNER_STATS.items()})
+    c.update(cell.counters())
     return c
 
 
@@ -268,30 +337,23 @@ def measure(spec: Spec, pim, seed: int, seconds: float, trace: bool,
     span = (jax.profiler.TraceAnnotation if trace
             else (lambda name: contextlib.nullcontext()))
 
-    # Warm-up: two jobs with inputs of their own. The first compiles; the
-    # second times a warm job.
-    warm = []
-    for i in WARM:
-        inp = cell.inputs(i)
-        t0 = time.perf_counter()
-        out = cell.run(inp, span)
-        warm.append(time.perf_counter() - t0)
-        del out
-    log(f"warm-up jobs {warm[0]:.3f} s (first, compiles) and "
-        f"{warm[1]:.6f} s")
+    warm_s = cell.warm_up(span)
 
     t0 = time.perf_counter()
-    n_ahead = int(math.ceil(seconds / warm[1] * AHEAD)) + 4
+    n_ahead = int(math.ceil(seconds / warm_s * AHEAD)) + 4
     ahead = [cell.inputs(i) for i in range(n_ahead)]
     gen_s = time.perf_counter() - t0
-    log(f"generator: {n_ahead} jobs' inputs made ahead of the window in "
-        f"{gen_s:.3f} s ({cell.commands_per_job} simulated commands a job)")
+    per = (f" ({cell.commands_per_job} simulated commands a job)"
+           if cell.commands_per_job else "")
+    log(f"generator: {n_ahead} calls' inputs made ahead of the window in "
+        f"{gen_s:.3f} s{per}")
     gc.collect()
     gc.freeze()         # the inputs made ahead stay out of every collection
 
     sampler = Reservoir(int(spec.traffic["check_jobs"]), seed)
-    c0 = counters()
+    c0 = counters(cell)
     jobs, failed, error = [], 0, None
+    commands = 0
     late = 0
     if trace:
         jax.profiler.start_trace(trace_dir)
@@ -307,18 +369,20 @@ def measure(spec: Spec, pim, seed: int, seconds: float, trace: bool,
         else:
             late += 1
             inp = cell.inputs(i)
-        t0 = time.perf_counter()
         try:
-            out = cell.run(inp, span)
+            done = cell.serve(inp, span)
         except Exception:            # noqa: BLE001 - reported, run fails
             failed += 1
             error = traceback.format_exc()
             break
-        t1 = time.perf_counter()
-        jobs.append((t0, t1))
-        sampler.offer(i, (inp, out))
-        del out
+        for d in done:
+            sampler.offer(len(jobs), (d.inp, d.out))
+            jobs.append((d.t_submit, d.t_done))
+            commands += d.commands
+        done = d = None     # outputs the sample did not keep go before the
+                            # next call (a job's output holds a device state)
         i += 1
+        t1 = time.perf_counter()
         if tracing and t1 - t_open >= TRACE_SECONDS:
             jax.profiler.stop_trace()
             tracing = False
@@ -328,11 +392,11 @@ def measure(spec: Spec, pim, seed: int, seconds: float, trace: bool,
     compiles.on = False
     if tracing:
         jax.profiler.stop_trace()
-    c1 = counters()
+    c1 = counters(cell)
     delta = {k: c1[k] - c0[k] for k in c1}
     attempted = len(jobs) + failed
     if late:
-        log(f"generator fell behind: {late} jobs' inputs were made inside "
+        log(f"generator fell behind: {late} calls' inputs were made inside "
             f"the window")
     log(f"window {t_close - t_open:.6f} s, {len(jobs)} jobs; in the "
         f"window: {compiles.n} backend compiles, jaxpr traces "
@@ -344,7 +408,7 @@ def measure(spec: Spec, pim, seed: int, seconds: float, trace: bool,
     peak = int(stats.get("peak_bytes_in_use", 0))
     for _, out in sampler.kept:
         fetch_state(out)
-    cell.device0 = None                 # the reference runs after the
+    cell.release()                      # the reference runs after the
     del ahead                           # program's state is freed
     gc.unfreeze()
     if sampler.kept:
@@ -352,9 +416,8 @@ def measure(spec: Spec, pim, seed: int, seconds: float, trace: bool,
         if line:
             log(line)
 
-    run = Run(seconds=t_close - t_open, jobs=jobs,
-              commands=cell.commands_per_job * len(jobs), setup_s=setup_s,
-              counters=delta, compiles=compiles.n)
+    run = Run(seconds=t_close - t_open, jobs=jobs, commands=commands,
+              setup_s=setup_s, counters=delta, compiles=compiles.n)
     t0 = time.perf_counter()
     limits = spec.traffic["limits"]
     numbers = check(cell, sampler.kept, limits) if sampler.kept else {}

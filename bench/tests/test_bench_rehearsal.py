@@ -30,16 +30,9 @@ def _span(name):
     return contextlib.nullcontext()
 
 
-def _jobs(spec, pim, seed, idx):
+def _jobs(spec, pim, seed, n):
     cell = spec.make_cell(pim, seed)
-    cell.make_device()
-    kept = []
-    for i in idx:
-        inp = cell.inputs(i)
-        out = cell.run(inp, _span)
-        harness.fetch_state(out)
-        kept.append((inp, out))
-    return cell, kept
+    return cell, harness.first_jobs(cell, n, _span)
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -47,7 +40,7 @@ def test_jobs_match_reference_and_control_fails(name, pim):
     spec = tiny_spec(name)
     limits = spec.traffic["limits"]
     for seed in (3, 2**31 + 11, 2**33 + 1):
-        cell, kept = _jobs(spec, pim, seed, (0, 1))
+        cell, kept = _jobs(spec, pim, seed, 2)
         nums = harness.check(cell, kept, limits)
         assert all(nums[k] <= lim for k, lim in limits.items()), nums
         ctrl = harness.check(cell, [(inp, None) for inp, _ in kept], limits,
@@ -64,7 +57,7 @@ def test_commands_per_job_counts_the_reference(name, pim):
     k = spec.traffic["shift_k"]
     assert cell.commands_per_job == 8 * (3 + k)
     # each SHIFT is metered as one shift of 4 AAPs
-    _, kept = _jobs(spec, pim, 1, (0,))
+    _, kept = _jobs(spec, pim, 1, 1)
     meter = kept[0][1]["meter"]
     assert meter["n_shift"].sum() == 8 * k
     assert meter["n_aap"].sum() == 8 * 4 * k
@@ -138,13 +131,15 @@ def test_measure_end_to_end(name, pim):
     assert run.compiles == 0
     assert run.counters["sched.dispatches"] == len(run.jobs)
     assert run.counters["sched.plan_misses"] == 0
+    assert run.counters["sched.payload_hits"] == 0
     assert run.counters["runner.traces"] == 0
     vals = harness.metric_values(run, spec.end_to_end)
     assert vals["sim_cmds_per_s"]["value"] > 0
     assert vals["setup_s"]["value"] > 0
     per = harness.metric_values(run, spec.per_layer)
     assert per == {"sched.dispatches_per_job": {"value": 1.0,
-                                                "unit": "count"}}
+                                                "unit": "count"},
+                   "sched.compiles_in_window": {"value": 0, "unit": "count"}}
 
 
 # -- faults the check has to catch -----------------------------------------
